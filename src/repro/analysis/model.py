@@ -34,7 +34,10 @@ model step            registry.py source
 ``r_parity``          ``_recover``'s trailing odd-``wseq`` repair
 ``wodd``/``weven``    ``_locked(write=True)`` seqlock counter bumps
 ``fold``              ``_fold_releases(tidx, pidx)``: one ring's
-                      ``held &= ~released; released = 0``
+                      ``held &= ~released``, from the bytes it read
+``fold_zero``         ``released[seen] = 0``: only those bytes (a
+                      fast-path byte stored in between waits for the
+                      next fold)
 ``chk``               publish occupancy check: held -> AgnocastQueueFull,
                       unreceived-only -> QoS drop, else quick free
 ``d_begin/apply/\
@@ -87,6 +90,13 @@ proving it can actually see the bugs it claims to guard against):
   ``waiter-flag-lost`` check; needs one mid-transaction kill).  This is
   the real registry bug found and fixed in this PR's audit — the model
   reproduces it schedule-for-schedule.
+* ``fold_zeroes_all`` — zero every release byte of the ring after the
+  fold, not just those it read: a fast-path release stored between the
+  read and the zeroing is lost, and its slot stays held with no
+  reference (invariant C, zero kills needed).  The registry did this:
+  on a serving replica's request ring, every publish into the slot was
+  refused once the ring wrapped back to it, and the head's publish
+  stalled.
 
 Run ``python -m repro.analysis.model --profile fast`` (CI) or
 ``--profile full`` for the 3-mutator / 2-kill sweep.
@@ -103,7 +113,7 @@ __all__ = ["Violation", "explore", "run_profile", "SCENARIOS", "PROFILES",
 
 BLOCK = object()            # step not enabled in this state (lock held)
 
-BUGS = ("no_dekker_recheck", "rollback_clobbers_waiters")
+BUGS = ("no_dekker_recheck", "rollback_clobbers_waiters", "fold_zeroes_all")
 
 
 class Violation(Exception):
@@ -231,10 +241,25 @@ def _restore_imgs(st, bug):
 
 
 def _fold(st, ring):
-    # _fold_releases(tidx, pidx): one publisher's ring only
-    for e in st["rings"][ring]["entries"]:
-        e["held"] -= e["rel"]
-        e["rel"].clear()
+    # _fold_releases(tidx, pidx) in one step: the janitor's pass
+    _fold_zero(st, ring, _fold_read(st, ring), None)
+
+
+def _fold_read(st, ring):
+    # _fold_releases(tidx, pidx), one publisher's ring only: the bytes
+    # it read, cleared from the held masks
+    seen = tuple(frozenset(e["rel"]) for e in st["rings"][ring]["entries"])
+    for e, rel in zip(st["rings"][ring]["entries"], seen):
+        e["held"] -= rel
+    return seen
+
+
+def _fold_zero(st, ring, seen, bug):
+    for e, rel in zip(st["rings"][ring]["entries"], seen):
+        if bug == "fold_zeroes_all":
+            e["rel"].clear()
+        else:
+            e["rel"] -= rel
 
 
 def _recover_steps(L, bug):
@@ -269,7 +294,10 @@ def op_publish(pid, k, *, ring, subs, bug):
         st["parity"] = True
 
     def fold(st, rg):
-        _fold(st, ring)
+        rg["seen"] = _fold_read(st, ring)
+
+    def fold_zero(st, rg):
+        _fold_zero(st, ring, rg.pop("seen"), bug)
 
     def _slot(st):
         r = st["rings"][ring]
@@ -337,7 +365,8 @@ def op_publish(pid, k, *, ring, subs, bug):
         pass
 
     return ([_acquire(pid, L + ".acquire")] + _recover_steps(L, bug) + [
-        (L + ".wodd", wodd), (L + ".fold", fold), (L + ".chk", chk),
+        (L + ".wodd", wodd), (L + ".fold", fold),
+        (L + ".fold_zero", fold_zero), (L + ".chk", chk),
         (L + ".d_begin", d_begin), (L + ".d_apply", d_apply),
         (L + ".d_clean", d_clean),
         (L + ".t_begin", t_begin), (L + ".e_fields", e_fields),
@@ -435,7 +464,10 @@ def op_release(pid, k, *, bug):
         # covers dying between here and .notify)
         if e["held"] and not (e["held"] - e["rel"]):
             rg["freed_pending"] = True
-        _fold(st, rg["q"][0])
+        rg["seen"] = _fold_read(st, rg["q"][0])
+
+    def l_fold_zero(st, rg):
+        _fold_zero(st, rg["q"][0], rg.pop("seen"), bug)
 
     def l_chk(st, rg):
         e = _slot(st, rg)
@@ -485,7 +517,8 @@ def op_release(pid, k, *, bug):
         (L + ".f_recheck", f_recheck),
         _acquire(pid, L + ".l_acq"),
     ] + _recover_steps(L, bug) + [
-        (L + ".wodd", wodd), (L + ".l_fold", l_fold), (L + ".l_chk", l_chk),
+        (L + ".wodd", wodd), (L + ".l_fold", l_fold),
+        (L + ".l_fold_zero", l_fold_zero), (L + ".l_chk", l_chk),
         (L + ".l_begin", l_begin), (L + ".l_store", l_store),
         (L + ".l_clean", l_clean), (L + ".weven", weven),
         (L + ".unlock", unlock), (L + ".notify", notify), (L + ".end", end),

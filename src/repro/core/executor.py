@@ -45,6 +45,7 @@ import traceback
 from collections import deque
 
 from repro.obs import metrics as _metrics
+from repro.obs.spans import span as _span
 from repro.obs.trace import Stage as _Stage
 
 __all__ = [
@@ -139,7 +140,9 @@ class _SubscriptionHandle(_Handle):
         self.fds = [sub.fileno()]
 
     def _on_ready(self, fd: int) -> list[_Work]:
-        ptrs = self.sub.take_all(self.batch)
+        with _span("take") as sp:
+            ptrs = self.sub.take_all(self.batch)
+            sp.set_metadata(n=len(ptrs))
         if self.batch is not None and len(ptrs) == self.batch:
             # a full batch may leave claimable messages behind, and their
             # wake tokens are already drained — ask the loop to re-poll us
@@ -632,7 +635,10 @@ class EventExecutor:
         delay = self._next_timer_delay(timeout)
         if works:
             delay = 0.0  # don't sleep on freshly re-polled work
-        for key, _ in self._sel.select(delay):
+        with _span("poll", timeout_us=-1 if delay is None
+                   else int(delay * 1e6)):
+            ready = self._sel.select(delay)
+        for key, _ in ready:
             if key.data is None:
                 self._drain_wake_pipe()
                 continue

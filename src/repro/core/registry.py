@@ -1194,11 +1194,15 @@ class Registry:
         idempotent and monotonic."""
         ring = self.entries[tidx] if pidx is None else self.entries[tidx, pidx]
         rel = ring["released"]
-        if not rel.any():
+        seen = rel != 0
+        if not seen.any():
             return
-        masks = _rel_masks(rel)
-        ring["held"][...] = ring["held"] & ~masks
-        rel[...] = 0
+        ring["held"][...] = ring["held"] & ~_rel_masks(seen)
+        # zero only the bytes folded: a fast-path release stored after
+        # ``seen`` was read waits for the next fold.  Zeroing every byte
+        # would lose it, and its held bit would outlive every reference,
+        # so the slot would never be published into again.
+        rel[seen] = 0
 
     def can_publish(self, tidx: int, pidx: int) -> bool:
         """Would :meth:`publish` succeed right now?  The target ring slot is

@@ -47,6 +47,7 @@ from .registry import (
 )
 from .smart_ptr import MessagePtr
 from repro.obs import trace as _trace
+from repro.obs.spans import span as _span
 
 # stage ids preloaded as plain ints: the traced hot path pays one
 # LOAD_GLOBAL per emit instead of a module+class attribute chain (which
@@ -370,7 +371,9 @@ class Publisher:
                     raise
                 step = left if should_stop is None else (
                     0.05 if left is None else min(0.05, left))
-                self.wait_for_slot(step)
+                with _span("slot_wait", step_us=-1 if step is None
+                           else int(step * 1e6)):
+                    self.wait_for_slot(step)
 
     # -- O(1) wake-ups -------------------------------------------------------------
 

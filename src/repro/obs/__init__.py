@@ -12,7 +12,7 @@ of payloads (the TZC rule applied to instrumentation).
 Observability
 =============
 
-Three layers, importable without jax:
+Four layers, importable without jax:
 
 **Trace events** (:mod:`repro.obs.trace`).  Each process lazily opens one
 single-writer shm ring buffer per domain (``agno-tr-<hash>-<pid>``) and
@@ -57,6 +57,16 @@ into a seqlock-guarded shm segment (``agno-mx-<hash>-<pid>``) so
 ``scripts/agno_top.py`` can render live per-topic / per-shard depth,
 throughput, and drop counters from outside the process.
 
+**Profiler spans** (:mod:`repro.obs.spans`).  The trace rings above run
+on ``CLOCK_MONOTONIC`` and cannot be lined up with a device trace.
+``span(name, **stats)`` marks the host states of a replica's round loop
+(the executor's poll and take, the results publish and its slot waits,
+the heartbeat, the admission splice, the decode sync, the slot retire)
+on the profiler's clock instead.  Unbound, it returns a shared no-op
+context; a JAX replica binds ``jax.profiler.TraceAnnotation`` with
+``use_profiler()``, and the spans are recorded whenever a profiler
+session runs, with no knob of their own.
+
 Env knobs (read when a tracer/exporter is first requested, so spawned
 children honour the environment they inherit):
 
@@ -72,6 +82,7 @@ history in :mod:`repro.core.registry`.
 from .flows import Flow, FlowAggregator
 from .metrics import (Counter, Gauge, MetricsExporter, MetricsRegistry,
                       counter, gauge, read_exports, snapshot)
+from .spans import span, use_profiler, use_sink
 from .trace import (STAGE_NAMES, TraceReader, TraceRing, Stage, enabled,
                     next_trace_id, tracer_for)
 
@@ -81,4 +92,5 @@ __all__ = [
     "Flow", "FlowAggregator",
     "Counter", "Gauge", "MetricsRegistry", "MetricsExporter",
     "counter", "gauge", "snapshot", "read_exports",
+    "span", "use_profiler", "use_sink",
 ]

@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core.device_arena import DevicePagePool
 from repro.models import Model
+from repro.obs.spans import span
 
 __all__ = ["Request", "Result", "InferenceServer", "attach_serving_executor",
            "make_steps"]
@@ -75,6 +76,7 @@ class Request:
     tokens: np.ndarray                  # prompt (unsized)
     max_new: int = 16
     stamp: float = field(default_factory=time.monotonic)
+    submitted: float = 0.0              # set by InferenceServer.submit
 
 
 @dataclass
@@ -82,7 +84,7 @@ class Result:
     rid: str
     tokens: list[int]
     prompt_len: int
-    ttft: float                          # time to first token
+    ttft: float                          # submission to first token
     latency: float
 
 
@@ -121,6 +123,7 @@ class InferenceServer:
     # -- request surface --------------------------------------------------------
 
     def submit(self, req: Request) -> None:
+        req.submitted = time.monotonic()
         self.queue.append(req)
 
     def cancel(self, rid: str) -> bool:
@@ -138,23 +141,25 @@ class InferenceServer:
     def _admit(self) -> None:
         while self.queue and self._free_slots:
             req = self.queue.popleft()
+            queued_us = int((time.monotonic() - req.submitted) * 1e6)
             slot = self._free_slots.pop()
             prompt = jnp.asarray(req.tokens, jnp.int32)[None, :]
-            t0 = time.monotonic()
             logits, cache1 = self._prefill(self._params, prompt)
             first = int(jnp.argmax(logits[0, -1]))
-            # prefill publishes this request's pages; decode subscribes.
-            npages = self.pool.pages_for_tokens(len(req.tokens) + req.max_new)
-            pages = self.pool.alloc(npages)
-            key = f"kv/{req.rid}"
-            self.pool.publish(key, pages, consumers=[f"decode/{req.rid}"])
-            self.pool.take(key, f"decode/{req.rid}")   # zero-copy receive
-            # splice the request's KV into its slot of the batched cache
-            self._cache = _splice_cache(self._cache, cache1, slot,
-                                        len(req.tokens))
+            ttft = time.monotonic() - req.submitted
+            with span("splice", len=len(req.tokens), queued_us=queued_us):
+                # prefill publishes this request's pages; decode subscribes
+                npages = self.pool.pages_for_tokens(len(req.tokens)
+                                                    + req.max_new)
+                pages = self.pool.alloc(npages)
+                key = f"kv/{req.rid}"
+                self.pool.publish(key, pages, consumers=[f"decode/{req.rid}"])
+                self.pool.take(key, f"decode/{req.rid}")  # zero-copy receive
+                # splice the request's KV into its slot of the batched cache
+                self._cache = _splice_cache(self._cache, cache1, slot,
+                                            len(req.tokens))
             st = {
-                "req": req, "key": key, "generated": [first],
-                "t0": t0, "ttft": time.monotonic() - t0,
+                "req": req, "key": key, "generated": [first], "ttft": ttft,
                 "gen": self._gate.current(req.rid), "chunk_seq": 0,
             }
             self._active[slot] = st
@@ -181,7 +186,8 @@ class InferenceServer:
                     prompt_len=len(st["req"].tokens), ttft=st["ttft"],
                     latency=time.monotonic() - st["req"].stamp)
         # zero the slot length so decode ignores it
-        self._cache["len"] = self._cache["len"].at[slot].set(0)
+        with span("retire"):
+            self._cache["len"] = self._cache["len"].at[slot].set(0)
         self._free_slots.append(slot)
 
     def _decode_round(self) -> None:
@@ -192,7 +198,8 @@ class InferenceServer:
             toks[slot, 0] = st["generated"][-1]
         nxt, self._cache = self._decode(self._params, self._cache,
                                         jnp.asarray(toks))
-        nxt = np.asarray(nxt)
+        with span("sync"):  # the host waits here while the device decodes
+            nxt = np.asarray(nxt)
         self.steps += 1
         for slot in list(self._active):
             st = self._active[slot]

@@ -37,6 +37,8 @@ import numpy as np
 from repro.core.executor import EventExecutor
 from repro.core.registry import AgnocastQueueFull
 from repro.core.topic import Domain
+from repro.obs import metrics as _metrics
+from repro.obs import spans as _spans
 from repro.obs import trace as _trace
 from repro.obs.metrics import MetricsExporter
 
@@ -196,10 +198,12 @@ def _build_jax_server(model: str, model_kwargs: dict | None, *, slots: int,
 
     import jax
 
-    from repro.obs import metrics as _metrics
     from repro.runtime.server import InferenceServer
 
     enable_compile_cache()
+    # the round loop's host spans become TraceMe events, recorded only
+    # while a profiler session runs
+    _spans.use_profiler()
     compiles = _metrics.counter("replica.compiles")
     cache_hits = _metrics.counter("replica.compile_cache_hits")
 
@@ -289,6 +293,9 @@ def replica_main(dom_name: str, shard: int, req_topic: str, res_topic: str, *,
     rows: list[ResRow] = []
     eos_pending = [False]
     rounds_unflushed = [0]
+    # publish_rows calls that returned with the rows kept (stopping, or the
+    # collector stalled past the timeout)
+    publish_timeouts = _metrics.counter("replica.publish_timeouts")
 
     # tracing (repro.obs): each SERVE_REQ row carries the head's trace id;
     # record rid -> tid at ingest (hop 1 = this replica) so every chunk the
@@ -318,21 +325,24 @@ def replica_main(dom_name: str, shard: int, req_topic: str, res_topic: str, *,
     server.stream_sink = sink
 
     def publish_rows():
-        loan = res_pub.borrow_loaded_message()
-        pack_results(loan, rows, shard=shard,
-                     depth=len(server.queue) + len(server._active),
-                     stamp=time.monotonic())
-        try:
-            got = res_pub.publish_blocking(loan, timeout=30.0,
-                                           should_stop=should_stop)
-        except AgnocastQueueFull:
-            got = None  # collector stalled past the timeout
+        with _spans.span("publish", rows=len(rows)) as sp:
+            loan = res_pub.borrow_loaded_message()
+            pack_results(loan, rows, shard=shard,
+                         depth=len(server.queue) + len(server._active),
+                         stamp=time.monotonic())
+            try:
+                got = res_pub.publish_blocking(loan, timeout=30.0,
+                                               should_stop=should_stop)
+            except AgnocastQueueFull:
+                got = None  # collector stalled past the timeout
+            sp.set_metadata(ok=int(got is not None))
         if got is None:
             # stopping or saturated: return the loan, KEEP the rows — the
             # next round's flush retries, and backpressure toward a wedged
             # collector must never crash the replica (mirrors
             # ShardRouter.flush on the request side)
             loan.dealloc()
+            publish_timeouts.inc()
             return
         rows.clear()
         eos_pending[0] = False
@@ -376,20 +386,21 @@ def replica_main(dom_name: str, shard: int, req_topic: str, res_topic: str, *,
     last_depth = [-1]
 
     def heartbeat():
-        dom.registry.refresh_lease(sub.tidx, sub.sidx)
-        if exporter is not None:
-            exporter.publish()  # live compile counts
-        depth = len(server.queue) + len(server._active)
-        if depth == 0 and not rows and last_depth[0] != 0:
-            loan = res_pub.borrow_loaded_message()
-            pack_results(loan, [], shard=shard, depth=0,
-                         stamp=time.monotonic())
-            try:
-                res_pub.publish(loan)
-            except AgnocastQueueFull:
-                loan.dealloc()  # collector lagging: it has fresher problems
-                return
-        last_depth[0] = depth
+        with _spans.span("heartbeat"):
+            dom.registry.refresh_lease(sub.tidx, sub.sidx)
+            if exporter is not None:
+                exporter.publish()  # live compile counts
+            depth = len(server.queue) + len(server._active)
+            if depth == 0 and not rows and last_depth[0] != 0:
+                loan = res_pub.borrow_loaded_message()
+                pack_results(loan, [], shard=shard, depth=0,
+                             stamp=time.monotonic())
+                try:
+                    res_pub.publish(loan)
+                except AgnocastQueueFull:
+                    loan.dealloc()  # collector lagging: fresher problems
+                    return
+            last_depth[0] = depth
 
     ex.add_timer(lease_period_s, heartbeat)
     if ready_event is not None:

@@ -300,6 +300,19 @@ def test_model_catches_rollback_waiter_clobber():
     assert any("kill(" in s for s in ei.value.trace)
 
 
+def test_model_catches_a_fold_that_zeroes_every_byte():
+    """A fold that zeroes every release byte, not just those it read,
+    loses a fast-path release stored in between; the publish that wraps
+    onto the slot is then refused, with no kill needed."""
+    with pytest.raises(model.Violation) as ei:
+        model.explore(model.SCENARIOS["pub_take_release"],
+                      bug="fold_zeroes_all")
+    assert ei.value.kind == "lost-release"
+    steps = [s.rsplit(".", 1)[-1] for s in ei.value.trace]
+    fold = steps.index("fold", steps.index("f_gate"))
+    assert steps[fold:].index("f_store") < steps[fold:].index("fold_zero")
+
+
 def test_model_cli_fast_profile():
     r = subprocess.run(
         [sys.executable, "-m", "repro.analysis.model",

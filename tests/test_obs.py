@@ -9,6 +9,8 @@ import gc
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -16,6 +18,7 @@ import pytest
 from repro.core import POINT_CLOUD2, Domain, EventExecutor
 from repro.obs import flows as F
 from repro.obs import metrics as M
+from repro.obs import spans as S
 from repro.obs import trace as T
 from repro.serving import (
     FleetController,
@@ -375,3 +378,167 @@ def test_agno_top_once_snapshot(dom):
         capture_output=True, text=True, timeout=60, env=env, cwd=_REPO)
     assert out.returncode == 0, out.stderr
     assert "obs/topic" in out.stdout
+
+
+# ---------------------------------------------------------------------------
+# profiler spans: the no-op default, the executor's poll/take, the replica's
+# publish path
+# ---------------------------------------------------------------------------
+
+
+class _RecordingSink:
+    """A span sink that keeps ``{"name": ..., **stats}`` per span, from
+    every thread."""
+
+    def __init__(self):
+        self.spans: list[dict] = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, **stats):
+        rec = {"name": name, **stats}
+        with self._lock:
+            self.spans.append(rec)
+        return _RecordedSpan(rec)
+
+    def named(self, name):
+        with self._lock:
+            return [s for s in self.spans if s["name"] == S.PREFIX + name]
+
+
+class _RecordedSpan:
+    def __init__(self, rec):
+        self.rec = rec
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **stats):
+        self.rec.update(stats)
+
+
+@pytest.fixture()
+def recorded_spans():
+    sink = _RecordingSink()
+    S.use_sink(sink)
+    try:
+        yield sink
+    finally:
+        S.use_sink(None)
+
+
+def test_span_without_a_sink_is_the_shared_null():
+    S.use_sink(None)
+    sp = S.span("poll", timeout_us=5)
+    assert sp is S.span("take") is S._NULL
+    with sp as inner:
+        inner.set_metadata(n=3)  # a no-op, like the span itself
+    sink = _RecordingSink()
+    S.use_sink(sink)
+    S.use_sink(None)
+    with S.span("take"):
+        pass
+    assert sink.spans == []  # unbound: nothing is built
+
+
+def test_executor_emits_poll_and_take(dom, recorded_spans):
+    pub = dom.create_publisher(POINT_CLOUD2, "obs/spans", depth=4)
+    sub = dom.create_subscription(POINT_CLOUD2, "obs/spans")
+    got = []
+    with EventExecutor(name="spans") as ex:
+        ex.add_subscription(sub, lambda ptr: got.append(len(ptr.data)))
+        for _ in range(2):
+            m = pub.borrow_loaded_message()
+            m.data.extend(np.ones(16, np.uint8))
+            pub.publish(m)
+        deadline = time.monotonic() + 5
+        while not got and time.monotonic() < deadline:
+            ex.spin_once(0.25)
+    assert got
+    polls = recorded_spans.named("poll")
+    assert polls and all(isinstance(p["timeout_us"], int) for p in polls)
+    assert polls[0]["timeout_us"] == 250_000
+    takes = recorded_spans.named("take")
+    assert takes and sum(t["n"] for t in takes) == 2
+
+
+def _wait(cond, timeout=20.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.01)
+
+
+def test_echo_replica_publish_path_spans_and_timeouts(monkeypatch,
+                                                      recorded_spans):
+    """An echo replica whose collector takes its results but never
+    releases them: the round publishes fill the results ring (an occupant
+    that was never taken would be dropped, keep-last) and wait for a slot;
+    stopping the replica returns the blocked publish unpublished, which
+    ``replica.publish_timeouts`` counts."""
+    import repro.serving.replica as rep
+    from repro.serving.messages import SERVE_RES
+
+    made = []
+
+    def counter(name, **labels):
+        c = M.registry.counter(name, **labels)
+        made.append(c)
+        return c
+
+    monkeypatch.setattr(rep._metrics, "counter", counter)
+    dom = Domain.create(arena_capacity=8 << 20)
+    router = ShardRouter(dom, [0], max_new=8)
+    stalled = dom.create_subscription(SERVE_RES, "obs/res")
+    held = []  # taken and never released
+
+    def stalled_slot_wait():
+        held.extend(stalled.take())
+        return recorded_spans.named("slot_wait")
+
+    stop, ready = threading.Event(), threading.Event()
+    th = threading.Thread(target=rep.replica_main, args=(
+        dom.name, 0, router.topic(0), "obs/res"), kwargs=dict(
+        model="echo", slots=2, max_new=8, depth=2, arena_mb=8,
+        lease_period_s=0.02, stop_event=stop, ready_event=ready))
+    th.start()
+    try:
+        assert ready.wait(20)
+        _wait(lambda: recorded_spans.named("heartbeat"))
+        for i in range(2):
+            router.submit(np.arange(4) + i)
+        router.flush()
+        _wait(stalled_slot_wait)
+    finally:
+        stop.set()
+        th.join(30)
+        for ptr in held:
+            ptr.release()
+        router.close()
+        stalled.close()
+        dom.close()
+    assert not th.is_alive()
+    waits = recorded_spans.named("slot_wait")
+    assert all(0 < w["step_us"] <= 50_000 for w in waits)
+    pubs = recorded_spans.named("publish")
+    # the idle heartbeat's empty beacon holds one of the ring's two slots
+    assert pubs[0]["ok"] == 1 and {p["ok"] for p in pubs} == {0, 1}
+    assert all(p["rows"] > 0 for p in pubs)
+    kept = [p for p in pubs if p["ok"] == 0]
+    (timeouts,) = [c for c in made if c.name == "replica.publish_timeouts"]
+    assert kept and timeouts.value == len(kept)
+
+
+def test_obs_and_core_import_without_jax():
+    code = ("import sys\n"
+            "import repro.obs, repro.obs.spans, repro.core\n"
+            "from repro.obs import span\n"
+            "with span('poll'):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(_REPO, "src"))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60, env=env, cwd=_REPO)
+    assert out.returncode == 0, out.stderr

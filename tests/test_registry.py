@@ -410,6 +410,35 @@ def test_fast_release_is_deferred_byte_store(reg):
     assert not e["released"].any()
 
 
+def test_fold_keeps_a_release_stored_while_it_runs(reg, monkeypatch):
+    """A fast-path release that lands while a lock holder folds is kept for
+    the next fold.  Zeroed with the bytes the fold read, it was lost: the
+    slot stayed held with no reference, and once the ring wrapped every
+    publish into it was refused."""
+    import repro.core.registry as R
+
+    t = reg.topic_index("x")
+    p = reg.add_publisher(t, os.getpid(), "a", depth=4)
+    s = reg.add_subscriber(t, os.getpid())
+    a, _ = reg.publish(t, p, 1, 1)
+    b, _ = reg.publish(t, p, 2, 1)
+    reg.take(t, s)
+    reg.release(t, p, s, a)                    # fast path: byte store
+    fold = R._rel_masks
+
+    def racing(rel):
+        out = fold(rel)
+        monkeypatch.setattr(R, "_rel_masks", fold)
+        reg.release(t, p, s, b)                # the subscriber, mid-fold
+        return out
+
+    monkeypatch.setattr(R, "_rel_masks", racing)
+    assert reg.reclaimable(t, p) == [a]        # the fold that raced
+    assert reg.reclaimable(t, p) == [b]        # b's release outlived it
+    for _ in range(4):                         # the ring wraps past both
+        reg.publish(t, p, 3, 1)
+
+
 def test_can_publish_counts_unfolded_release_intent(reg):
     """The waiter-side re-check reads release bytes: a fast-path release
     that raced the flag arming is still visible to can_publish."""

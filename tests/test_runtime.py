@@ -154,3 +154,20 @@ def test_server_cancel_janitor(tiny_server):
     results = srv.serve()
     assert "survivor" in results and "victim" not in results
     assert srv.pool.free_pages == srv.pool.num_pages
+
+
+def test_server_ttft_counts_queue_wait(tiny_server):
+    """``Result.ttft`` runs from submission, so time a request spent queued
+    before admission is part of it."""
+    srv, _ = tiny_server
+    rng = np.random.default_rng(3)
+    srv.submit(Request(rid="warm", tokens=rng.integers(0, 512, 8), max_new=2))
+    srv.serve()  # compiles this prompt length outside the timed requests
+    for i in range(3):
+        srv.submit(Request(rid=f"q{i}", tokens=rng.integers(0, 512, 8),
+                           max_new=3))
+    time.sleep(0.2)
+    results = srv.serve()
+    for i in range(3):
+        r = results[f"q{i}"]
+        assert 0.2 <= r.ttft < r.latency
