@@ -16,6 +16,12 @@ same pass runs with every matmul operand rounded to float8 (e4m3, scaled
 per row), the next precision below the configuration's bfloat16; the
 control's reading is the reference's gap for the token the float8 pass
 puts first.
+
+These are the dense decoder's.  A configuration that names an
+architecture module (``bench/spec.py`` ``load_architecture``) gets that
+module's ``init_weights`` and ``forward_logits`` in ``Reference``; the
+blocking, padding, gaps and control stay this file's.  A module may use
+``fp8`` for its control.
 """
 
 from __future__ import annotations
@@ -78,7 +84,7 @@ def init_weights(c: dict, seed: int):
     return jax.jit(init)(jax.random.PRNGKey(seed))
 
 
-def _fp8(x, axis):
+def fp8(x, axis):
     """Round to float8 e4m3 with one scale per slice along ``axis``."""
     import jax.numpy as jnp
 
@@ -100,7 +106,7 @@ def forward_logits(w, tokens, pos, c: dict, control: bool):
     s = tokens.shape[0]
     if control:
         def mm(spec, a, b, a_axis, b_axis):
-            return jnp.einsum(spec, _fp8(a, a_axis), _fp8(b, b_axis))
+            return jnp.einsum(spec, fp8(a, a_axis), fp8(b, b_axis))
     else:
         def mm(spec, a, b, a_axis, b_axis):
             return jnp.einsum(spec, a, b)
@@ -162,14 +168,21 @@ def forward_logits(w, tokens, pos, c: dict, control: bool):
 
 class Reference:
     """The reference on this process's default device (the head runs it
-    after every replica has exited, so the chip is free)."""
+    after every replica has exited, so the chip is free), of the
+    architecture the configuration names."""
 
     def __init__(self, c: dict, seed: int):
         import jax
 
+        from bench import spec
+
+        arch = spec.architecture(c)
+        self.init_weights = init_weights if arch is None else arch.init_weights
+        self.forward_logits = (forward_logits if arch is None
+                               else arch.forward_logits)
         jax.config.update("jax_default_matmul_precision", "highest")
         self.c = c
-        self.w = init_weights(c, seed)
+        self.w = self.init_weights(c, seed)
         self._fns = {}
 
     def _fn(self, control: bool):
@@ -177,7 +190,7 @@ class Reference:
         import jax.numpy as jnp
 
         if control not in self._fns:
-            c = self.c
+            c, forward_logits = self.c, self.forward_logits
 
             def run(w, tokens, pos, served):
                 logits = forward_logits(w, tokens, pos, c, False)

@@ -51,8 +51,14 @@ CACHE_DIR = ROOT / ".jax_cache"       # fixed: the path is part of the key
 RUNS_DIR = ROOT / ".perfbench"
 READY_TIMEOUT_S = 1200.0              # a cold first run compiles
 WARM_TIMEOUT_S = 900.0
-TRACE_TIMEOUT_S = 120.0
 TRACE_S = 20.0                        # traced span, centred in the window
+# how long the head waits, after the window's requests have finished, for
+# each replica to stop its trace and write it: the profiler's stop_trace
+# grows with the host events of the traced span (92-99 s for 20 s of the
+# chat cell on a v5e host), so the wait grows with the span; the replicas
+# stop theirs side by side, so it does not grow with their count
+TRACE_WAIT_S = 60.0
+TRACE_WAIT_PER_TRACED_S = 9.0
 PUBLISH_TIMEOUT_S = 60.0
 
 
@@ -213,14 +219,18 @@ def verdict(checks: dict) -> bool:
     return all(v <= lim for v, lim in checks.values())
 
 
-def trace_wait(run_dir: Path, done: str, n: int, client: Client) -> None:
+def trace_wait(run_dir: Path, done: str, n: int, client: Client,
+               trace_s: float) -> None:
     """Pump results until every replica has confirmed ``done``."""
-    deadline = time.monotonic() + TRACE_TIMEOUT_S
+    wait_s = TRACE_WAIT_S + TRACE_WAIT_PER_TRACED_S * trace_s
+    t = time.monotonic()
     while not all((run_dir / f"trace_{done}-{s}").exists() for s in range(n)):
-        if time.monotonic() > deadline:
+        if time.monotonic() > t + wait_s:
             raise RuntimeError(f"replicas did not confirm trace {done} in "
-                               f"{TRACE_TIMEOUT_S} s")
+                               f"{wait_s} s")
         client.pump(0.01)
+    log(f"trace {done} confirmed {time.monotonic() - t:.1f} s into the "
+        f"{wait_s} s wait")
 
 
 def compiles(pool, n: int) -> tuple[int, int]:
@@ -373,7 +383,7 @@ def measure(args, cell, shape, serving, limits, weight_seed, run_dir):
             client.wait(rids, t1 + float(traffic["follow_s"]))
             end = time.monotonic()
             if args.trace:
-                trace_wait(run_dir, "stopped", n, client)
+                trace_wait(run_dir, "stopped", n, client, trace_s)
             c1, _ = compiles(pool, n)
             metrics = e2e_metrics(client, rids, end)
             log(f"end to end: {json.dumps(metrics)}")

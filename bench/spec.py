@@ -1,15 +1,21 @@
-"""Find a cell's configuration, traffic mix and metrics by name.
+"""Find a cell's configuration, traffic mix, metrics and architecture by
+name.
 
 A cell (``BENCHMARK.json`` ``workloads`` entry) names a configuration and
 a traffic mix; each is a JSON file under ``bench/configs/`` and
 ``bench/traffic/``.  A per-layer metric is a module ``bench/metrics/
 <name>.py`` that defines ``LAYER``, ``MOVES``, ``MATCH`` and ``read(ctx)``.
-Adding a cell, a mix or a metric adds files and entries; no code here
-changes.
+A configuration of another architecture than the dense Qwen2/Qwen3
+decoder names a module ``bench/arch/<name>.py`` under its key
+``architecture`` (its reference and work counts), and maps further
+published keys to the program's ``ModelConfig`` fields under its key
+``program_keys``.  Adding a cell, a mix, a metric or an architecture adds
+files and entries; no code here changes.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass
@@ -17,8 +23,10 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
+ARCH_DIR = HERE / "arch"
 
-# the configuration file's published keys -> the program's ModelConfig names
+# the configuration file's published keys -> the program's ModelConfig
+# names; a configuration's own "program_keys" are merged over these
 PROGRAM_KEYS = {
     "num_hidden_layers": "num_layers",
     "hidden_size": "d_model",
@@ -84,18 +92,57 @@ def load_metric(name: str):
     return mod
 
 
+def load_architecture(name: str):
+    """The module ``bench/arch/<name>.py`` that a configuration's
+    ``architecture`` key names.  It defines the reference,
+    ``init_weights(c, seed)`` and ``forward_logits(w, tokens, pos, c,
+    control)``, and may define any of ``bench/work.py``'s counts, each of
+    which replaces the dense one for that configuration."""
+    return _load_module(ARCH_DIR / f"{name}.py")
+
+
+@functools.lru_cache(maxsize=None)
+def _load_module(path: Path):
+    if not path.exists():
+        raise FileNotFoundError(f"no architecture module {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"perfbench_arch_{path.stem.replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(config: dict):
+    """The architecture module a configuration names, or ``None`` for the
+    dense Qwen2/Qwen3 decoder of ``bench/reference.py``."""
+    name = config.get("architecture")
+    return None if name is None else load_architecture(name)
+
+
+def program_keys(config: dict) -> dict:
+    """Published key -> ``ModelConfig`` field: ``PROGRAM_KEYS`` with the
+    configuration's own ``program_keys`` merged over it."""
+    return {**PROGRAM_KEYS, **config.get("program_keys", {})}
+
+
 def program_kwargs(config: dict, reduced, *, rehearse: bool = False) -> dict:
     """``model_kwargs`` for the replica: the program's architecture name
     plus every key the cell cuts (``reduced``) or, in a CPU rehearsal,
-    every width of the rehearsal block."""
+    every mapped key of the shape with the rehearsal block laid over it."""
+    keys = program_keys(config)
     kw = {"arch": config["program_arch"]}
-    keys = list(reduced)
-    src = config
     if rehearse:
         src = model_shape(config, rehearse=True)
-        keys = list(PROGRAM_KEYS)
-    for k in keys:
-        kw[PROGRAM_KEYS[k]] = src[k]
+        for k in keys:
+            if k in src:
+                kw[keys[k]] = src[k]
+        return kw
+    for k in reduced:
+        if k not in keys:
+            raise KeyError(f"reduced key {k!r} is in neither PROGRAM_KEYS nor "
+                           f"the configuration's program_keys (have "
+                           f"{sorted(keys)})")
+        kw[keys[k]] = config[k]
     return kw
 
 

@@ -40,6 +40,92 @@ def test_qwen2_counts():
     assert work.decode_bytes(c, 32, 40_000) == 4_235_034_624
 
 
+def test_qwen3_8b_9l_counts():
+    c = shape("qwen3-8b-9l")
+    assert work.layer_params(c) == 192_937_984
+    # nine layers and the untied head; the embedding is gathered
+    assert work.weight_bytes(c) == 4_717_543_424
+    assert work.kv_bytes_per_token(c) == 36_864       # 36 KiB
+    assert work.decode_bytes(c, 16, 30_000) == (
+        4_717_543_424 + 16 * 4096 * 2 + 30_016 * 36_864)
+
+
+COUNTS = ("linear_flops_per_token", "weight_bytes", "kv_bytes_per_token",
+          "prefill_flops", "prefill_bytes", "decode_flops", "decode_bytes")
+
+# a stand-in architecture module: its reference puts the logit 2.0 on the
+# token after the one at each position, and it replaces two counts
+STUB = """
+import jax.numpy as jnp
+
+
+def init_weights(c, seed):
+    return {"scale": jnp.float32(2.0)}
+
+
+def forward_logits(w, tokens, pos, c, control):
+    nxt = (tokens[pos] + 1) % c["vocab_size"]
+    return w["scale"] * (jnp.arange(c["vocab_size"])[None] == nxt[:, None])
+
+
+def weight_bytes(c):
+    return 1000
+
+
+def decode_bytes(c, n_active, ctx_sum):
+    return 7 * n_active + ctx_sum
+"""
+
+
+@pytest.fixture
+def stub_arch(tmp_path, monkeypatch):
+    """A configuration that names the stand-in module ``stub``."""
+    (tmp_path / "stub.py").write_text(STUB)
+    monkeypatch.setattr(spec, "ARCH_DIR", tmp_path)
+    return dict(shape("qwen2-1.5b"), architecture="stub")
+
+
+def test_architecture_module_replaces_the_counts_it_defines(stub_arch):
+    dense = shape("qwen2-1.5b")
+    assert work.decode_bytes(stub_arch, 3, 100) == 121
+    assert work.weight_bytes(stub_arch) == 1000
+    # the dense counts it leaves stay, and use the ones it replaced
+    assert work.decode_flops(stub_arch, 3, 100) == work.decode_flops(dense, 3, 100)
+    assert (work.prefill_bytes(stub_arch, 64) - work.prefill_bytes(dense, 64)
+            == 1000 - work.weight_bytes(dense))
+
+
+def test_architecture_module_is_the_reference(stub_arch):
+    from bench.reference import Reference
+
+    ref = Reference(stub_arch, 5)
+    assert float(ref.w["scale"]) == 2.0
+    prompt = np.array([10, 11, 12], np.int32)
+    # the stub's best after 12 is 13, after 13 is 14; the third is off
+    got = ref.gaps(prompt, [13, 14, 99], n_pos=8)
+    assert list(got["served"]) == [0.0, 0.0, 2.0]
+
+
+def test_configuration_without_architecture_is_the_dense_decoder():
+    """Each count is the dense one to the last digit, and the reference
+    is the dense decoder's."""
+    from bench import reference
+
+    for name in ("qwen2-1.5b", "qwen3-8b-9l"):
+        c = shape(name)
+        assert "architecture" not in c and spec.architecture(c) is None
+        for count in COUNTS:
+            fn = getattr(work, count)
+            args = {"prefill_flops": (1536,), "prefill_bytes": (1536,),
+                    "decode_flops": (16, 30_000),
+                    "decode_bytes": (16, 30_000)}.get(count, ())
+            assert fn(c, *args) == fn.dense(c, *args), (name, count)
+    c = dict(shape("qwen2-1.5b"), **TINY)
+    ref = reference.Reference(c, 1)
+    assert ref.init_weights is reference.init_weights
+    assert ref.forward_logits is reference.forward_logits
+
+
 def test_least_time_takes_the_binding_bound():
     peak = work.peaks("TPU v5 lite")
     assert work.least_time(197e12, 0, peak) == pytest.approx(1.0)
@@ -55,7 +141,7 @@ def test_unknown_device_kind_raises():
 # -- traffic --------------------------------------------------------------------
 
 @pytest.mark.parametrize(
-    "mix", sorted({w["traffic"] for w in spec.load_benchmark()["workloads"]}))
+    "mix", sorted(p.stem for p in (spec.HERE / "traffic").glob("*.json")))
 def test_traffic_is_the_seeds_order_of_one_workload(mix):
     t = spec.load_json(spec.ROOT / f"bench/traffic/{mix}.json")
     a = generate(t, 2**33 + 7, 30, 151_936)
@@ -181,8 +267,10 @@ QWEN3 = {"program_arch": "qwen3-8b", "qk_norm": True,
          "tie_word_embeddings": False, "attention_bias": False}
 
 
-@pytest.mark.parametrize("arch", [{}, QWEN3], ids=["qwen2", "qwen3"])
-def test_reference_matches_the_served_models_definition(arch):
+@pytest.mark.parametrize("config, arch", [
+    ("qwen2-1.5b", {}), ("qwen2-1.5b", QWEN3), ("qwen3-8b-9l", {})],
+    ids=["qwen2", "qwen3", "qwen3-8b-9l"])
+def test_reference_matches_the_served_models_definition(config, arch):
     """At tiny widths in float32: the reference's seeded weights are the
     program's, value for value, and its logits agree with the program's
     own forward pass."""
@@ -192,7 +280,7 @@ def test_reference_matches_the_served_models_definition(arch):
     from bench.reference import SEQ_BLOCK, forward_logits, init_weights
     from repro.serving.replica import build_model
 
-    c = dict(shape("qwen2-1.5b"), **arch, **TINY)
+    c = dict(shape(config), **arch, **TINY)
     kw = dict(spec.program_kwargs(c, [], rehearse=False),
               **{spec.PROGRAM_KEYS[k]: v for k, v in TINY.items()},
               param_dtype="float32", compute_dtype="float32")
@@ -216,6 +304,35 @@ def test_reference_matches_the_served_models_definition(arch):
         want = np.asarray(m.forward(rounded, {"tokens": tokens[None]})[0][0])[pos]
         got = np.asarray(forward_logits(ref, tokens, pos, c, control=False))
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
+
+
+def test_program_keys_reach_the_replica():
+    """A ``program_keys`` entry outside the seven reaches ``model_kwargs``
+    where the cell cuts it and in the rehearsal, and names a field of the
+    program's ``ModelConfig``."""
+    from repro.configs import get_config
+
+    c = dict(shape("qwen2-1.5b"), num_experts=60,
+             program_keys={"num_experts": "num_experts"})
+    c["rehearse"] = dict(c["rehearse"], shape=dict(c["rehearse"]["shape"],
+                                                   num_experts=4))
+    kw = spec.program_kwargs(c, ["num_experts"])
+    assert kw == {"arch": "qwen2-1.5b", "num_experts": 60}
+    rehearsal = spec.program_kwargs(c, [], rehearse=True)
+    assert rehearsal["num_experts"] == 4
+    assert {rehearsal[spec.PROGRAM_KEYS[k]] for k in TINY} == set(TINY.values())
+    for kw in (kw, rehearsal):
+        kw = dict(kw)
+        assert get_config(kw.pop("arch")).scaled(**kw).num_experts == kw[
+            "num_experts"]
+
+
+def test_unmapped_reduced_key_raises():
+    c = shape("qwen2-1.5b")
+    assert spec.program_kwargs(c, ["num_hidden_layers"]) == {
+        "arch": "qwen2-1.5b", "num_layers": 28}
+    with pytest.raises(KeyError, match="program_keys"):
+        spec.program_kwargs(c, ["num_experts"])
 
 
 def test_control_departs_from_the_reference():

@@ -32,8 +32,12 @@ def rehearse(*extra, workload="qwen2-1.5b.chat", seconds=2, trace=0):
     return json.loads(p.stdout.strip().splitlines()[-1]), p.stderr
 
 
-def test_rehearsal_reports_no_device_metric():
-    line, err = rehearse(trace=1)
+WORKLOADS = [w["name"] for w in spec.load_benchmark()["workloads"]]
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_rehearsal_reports_no_device_metric(workload):
+    line, err = rehearse(trace=1, workload=workload)
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True
     assert line["metrics"] == {}
@@ -44,13 +48,14 @@ def test_rehearsal_reports_no_device_metric():
     assert "check logit_gap" in err
 
 
+@pytest.mark.parametrize("workload", WORKLOADS)
 @pytest.mark.parametrize("fault, check", [
     ("alter_token", "logit_gap"),      # a token altered where it is produced
     ("stale_cache", "logit_gap"),      # the slot's state left unchanged
     ("drop_request", "missing"),       # an answer that never comes
 ])
-def test_broken_timed_path_fails_a_check(fault, check):
-    line, _ = rehearse("--fault", fault)
+def test_broken_timed_path_fails_a_check(fault, check, workload):
+    line, _ = rehearse("--fault", fault, workload=workload)
     got = line["checks"][check]
     assert got["value"] > got["limit"]
     assert line["correct"] is False
@@ -58,8 +63,7 @@ def test_broken_timed_path_fails_a_check(fault, check):
         assert got["value"] == 1 and line["failed"] == 1
 
 
-@pytest.mark.parametrize(
-    "workload", [w["name"] for w in spec.load_benchmark()["workloads"]])
+@pytest.mark.parametrize("workload", WORKLOADS)
 def test_float8_control_in_the_programs_place_is_not_correct(workload):
     """The float8 control's first choices are judged by the comparison
     that decides ``correct``, on the sample the program's tokens pass."""

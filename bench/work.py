@@ -6,16 +6,39 @@ real length (not ``max_seq``) and only the last prompt position is
 unembedded.  A later change that reads less is judged against the same
 work.  Norm scales and biases are left out (under 0.1% of either count).
 Weights and cache are bfloat16 (2 bytes), as the configurations state.
+
+The counts here are the dense Qwen2/Qwen3 decoder's.  A configuration
+that names an architecture module (``bench/spec.py``
+``load_architecture``) gets that module's function in place of each count
+it defines; the others stay dense, and call the module's where they use a
+count it replaced.  The dense function stays reachable as ``.dense``.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 from pathlib import Path
+
+from bench import spec
 
 BYTES = 2  # bfloat16 weights and cache
 
 _PEAKS = Path(__file__).resolve().parent / "peaks.json"
+
+
+def by_architecture(dense):
+    """A count the configuration's architecture module may replace with a
+    function of the same name."""
+    name = dense.__name__
+
+    @functools.wraps(dense)
+    def count(c: dict, *args):
+        own = getattr(spec.architecture(c), name, None)
+        return (own or dense)(c, *args)
+
+    count.dense = dense
+    return count
 
 
 def dims(c: dict):
@@ -30,6 +53,7 @@ def layer_params(c: dict) -> int:
     return d * hd * (h + 2 * kv) + h * hd * d + 3 * d * ff
 
 
+@by_architecture
 def weight_bytes(c: dict) -> int:
     """Weights a whole-model pass reads: every layer and the output head
     (the input embedding contributes only its gathered rows)."""
@@ -37,11 +61,13 @@ def weight_bytes(c: dict) -> int:
     return (n_layers * layer_params(c) + vocab * d) * BYTES
 
 
+@by_architecture
 def kv_bytes_per_token(c: dict) -> int:
     n_layers, _, _, kv, hd, _, _ = dims(c)
     return n_layers * 2 * kv * hd * BYTES
 
 
+@by_architecture
 def linear_flops_per_token(c: dict) -> int:
     return 2 * dims(c)[0] * layer_params(c)
 
@@ -52,6 +78,7 @@ def attn_flops(c: dict, n_keys: int) -> int:
     return n_layers * 4 * h * hd * n_keys
 
 
+@by_architecture
 def prefill_flops(c: dict, s: int) -> int:
     """One prompt of ``s`` tokens: causal attention, logits of the last
     position only."""
@@ -61,12 +88,14 @@ def prefill_flops(c: dict, s: int) -> int:
             + n_layers * 4 * h * hd * causal_pairs + 2 * d * vocab)
 
 
+@by_architecture
 def prefill_bytes(c: dict, s: int) -> int:
     """Weights once, the prompt's embedding rows, the cache it writes."""
     d = dims(c)[1]
     return weight_bytes(c) + s * d * BYTES + s * kv_bytes_per_token(c)
 
 
+@by_architecture
 def decode_flops(c: dict, n_active: int, ctx_sum: int) -> int:
     """One step for ``n_active`` slots whose cached lengths sum to
     ``ctx_sum``: each attends over its cache plus the new token."""
@@ -75,6 +104,7 @@ def decode_flops(c: dict, n_active: int, ctx_sum: int) -> int:
     return n_active * per_tok + attn_flops(c, ctx_sum + n_active)
 
 
+@by_architecture
 def decode_bytes(c: dict, n_active: int, ctx_sum: int) -> int:
     """Weights once, each active slot's cache at its real length, and the
     new token's cache entry."""
